@@ -256,8 +256,9 @@ N_LAYERS = 4
 
 def _probe_subprocess(flag: str, print_fn):
     """Run a child probe on 8 fake CPU devices (XLA_FLAGS must be set before
-    the child's first jax call)."""
-    env = dict(os.environ,
+    the child's first jax call). The child is pinned to the CPU: a chip
+    belongs to one process, and this parent has already imported jax."""
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
                XLA_FLAGS="--xla_force_host_platform_device_count=8")
     # invoke by file path, not -m: the benchmarks dir isn't an installed
     # package and -m would silently depend on the parent's cwd

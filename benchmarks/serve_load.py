@@ -21,6 +21,9 @@ Two phases on the same reduced model over 8 fake devices:
 
     PYTHONPATH=src python -m benchmarks.serve_load          # full storm
     PYTHONPATH=src python -m benchmarks.serve_load --quick  # CI leg
+
+CPU only for now: it forces 8 fake CPU devices through XLA_FLAGS, and its
+wall-clock numbers are CPU numbers, not device metrics.
 """
 from __future__ import annotations
 

@@ -1,108 +1,58 @@
-"""Version-compatibility shims for the JAX APIs this repo straddles.
+"""The version-sensitive JAX surface, in one file (jax 0.9.0).
 
-The code is written against the modern surface (``jax.shard_map`` with
-``check_vma=``, ``jax.sharding.AxisType``, ``lax.pvary``) but must run on
-stock JAX down to 0.4.35 (CI pins 0.4.37, where none of those exist yet).
-Every call site imports the shim instead of feature-testing locally, so the
-supported-version policy lives in exactly one file (see README "Supported
-JAX versions").
+The repo is written against one installed JAX release (README "Supported
+JAX versions"). The APIs that have moved between releases are reached only
+through this module, so a future upgrade touches one file; the
+``version-api`` lint rule (``repro.analysis.lint``) keeps other modules
+from importing them directly.
 
-  make_mesh(shape, axes)   jax.make_mesh with axis_types= when available;
-                           plain jax.make_mesh on 0.4.35-0.4.x; explicit
-                           Mesh(np.array(devices).reshape(shape)) pre-0.4.35.
-  shard_map(...)           jax.shard_map(check_vma=...) when available, else
-                           jax.experimental.shard_map.shard_map mapping
-                           check_vma -> check_rep (same meaning: verify the
-                           replication claims of out_specs).
-  pvary(x, axes)           lax.pvary when the varying-manual-axes type system
-                           exists; identity otherwise (pre-0.5 shard_map has
-                           no device-variance types, so it is already a no-op).
+  make_mesh(shape, axes)   a Mesh with Auto axis types, over the default
+                           devices or over ``devices`` in the given order.
+  shard_map(...)           ``jax.shard_map``.
+  pvary(x, axes)           mark x device-varying over ``axes``.
+  new_primitive(name)      a ``jax.extend.core.Primitive``.
 """
 from __future__ import annotations
 
-import inspect
+import os
 
 import jax
 import numpy as np
-
-try:  # jax >= 0.5.x
-    from jax.sharding import AxisType
-except ImportError:  # stock 0.4.x
-    AxisType = None
-
-# jax.shard_map was promoted to the jax namespace before check_rep was
-# renamed to check_vma, so the presence of the attribute alone doesn't pin
-# the kwarg — read it off the signature once.
-_SM_CHECK_KW = None
-if hasattr(jax, "shard_map"):
-    _SM_CHECK_KW = ("check_vma"
-                    if "check_vma" in inspect.signature(jax.shard_map).parameters
-                    else "check_rep")
+from jax.extend.core import Primitive
+from jax.sharding import AxisType, Mesh
 
 
-def make_mesh(shape, axes):
-    """Build a Mesh over the default devices, newest API first."""
-    if AxisType is not None:
-        return jax.make_mesh(shape, axes,
-                             axis_types=(AxisType.Auto,) * len(axes))
-    if hasattr(jax, "make_mesh"):  # 0.4.35+: no axis_types kwarg yet
-        return jax.make_mesh(shape, axes)
-    from jax.sharding import Mesh
-    n = int(np.prod(shape))
-    return Mesh(np.asarray(jax.devices()[:n]).reshape(shape), axes)
+def make_mesh(shape, axes, devices=None):
+    """Mesh over ``devices`` (kept in the order given) or, when None, over
+    the default devices in ``jax.make_mesh``'s order."""
+    types = (AxisType.Auto,) * len(axes)
+    if devices is None:
+        return jax.make_mesh(shape, axes, axis_types=types)
+    return Mesh(np.asarray(devices).reshape(shape), axes, axis_types=types)
 
 
 def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = True):
-    """``jax.shard_map`` across JAX versions (check_vma == old check_rep)."""
-    if _SM_CHECK_KW is not None:
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs,
-                             **{_SM_CHECK_KW: check_vma})
-    from jax.experimental.shard_map import shard_map as _shard_map
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_rep=check_vma)
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+                         check_vma=check_vma)
 
 
 def pvary(x, axes):
-    """Mark x device-varying over `axes`; identity where the type system
-    (and hence the distinction) does not exist."""
+    """Mark x device-varying over `axes`."""
     if not axes:
         return x
-    from jax import lax
-    if hasattr(lax, "pvary"):
-        return lax.pvary(x, tuple(axes))
-    return x
+    return jax.lax.pcast(x, tuple(axes), to="varying")
 
 
 def new_primitive(name: str):
-    """A ``jax.core.Primitive`` across the extend-API migration.
-
-    ``jax.core.Primitive`` moved to ``jax.extend.core`` (the old path warns
-    and is slated for removal); this is the one place that knows which spelling
-    the installed JAX uses.
-    """
-    try:  # jax >= 0.4.34: the supported public surface
-        from jax.extend.core import Primitive
-    except ImportError:  # older releases
-        from jax.core import Primitive
     return Primitive(name)
 
 
-def enable_cpu_collectives(impl: str = "gloo") -> bool:
+def enable_cpu_collectives(impl: str = "gloo") -> None:
     """Select the cross-process collectives backend for the CPU client.
 
     Must run before the first jax device access AND before
     ``jax.distributed.initialize`` — without it, a multi-process CPU cluster
-    forms but every cross-host collective deadlocks. Returns False on JAX
-    versions that predate the option (single-process use is unaffected;
-    multi-process runs will fail loudly at initialize time instead).
+    forms but every cross-host collective deadlocks.
     """
-    import os
-    # the env var is the config's backing store on every version that has
-    # the option; setting both covers config-name churn across releases
     os.environ.setdefault("JAX_CPU_COLLECTIVES_IMPLEMENTATION", impl)
-    try:
-        jax.config.update("jax_cpu_collectives_implementation", impl)
-        return True
-    except (AttributeError, ValueError):
-        return False
+    jax.config.update("jax_cpu_collectives_implementation", impl)

@@ -19,6 +19,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .quant_int4 import pack_nibbles
+
 
 def _dequant_matmul_kernel(x_ref, q_ref, s_ref, o_ref, acc_ref, *, k_steps):
     @pl.when(pl.program_id(2) == 0)
@@ -173,7 +175,7 @@ INT8_QMAX = 127.0
 INT4_QMAX = 7.0
 
 
-def _matmul_quant_kernel(x_ref, g_ref, q_ref, s_ref, acc_ref, *,
+def _matmul_quant_kernel(x_ref, g_ref, q_ref, s_ref, acc_ref, *pack_ref,
                          block, bits, m_steps):
     @pl.when(pl.program_id(2) == 0)
     def _init():
@@ -198,8 +200,9 @@ def _matmul_quant_kernel(x_ref, g_ref, q_ref, s_ref, acc_ref, *,
         qv = jnp.clip(jnp.round(a3 / scales), -qmax, qmax)
         s_ref[...] = scales.reshape(r, c // block)
         if bits == 4:
-            pairs = (qv.astype(jnp.int32) + 8).reshape(r, c // 2, 2)
-            q_ref[...] = (pairs[..., 0] | (pairs[..., 1] << 4)).astype(jnp.uint8)
+            # pack_ref: the (bn, bk) scratch only INT4 allocates
+            q_ref[...] = pack_nibbles(qv.reshape(r, c).astype(jnp.int32) + 8,
+                                      *pack_ref)
         else:
             q_ref[...] = qv.reshape(r, c).astype(jnp.int8)
 
@@ -245,6 +248,7 @@ def matmul_quant_pallas(x: jnp.ndarray, g: jnp.ndarray, *, block: int,
         ],
         out_shape=[q_shape,
                    jax.ShapeDtypeStruct((k, n // block), jnp.float32)],
-        scratch_shapes=[pltpu.VMEM((bk, bn), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((bk, bn), jnp.float32)]
+        + ([pltpu.VMEM((bn, bk), jnp.int32)] if bits == 4 else []),
         interpret=interpret,
     )(x, g)

@@ -9,8 +9,10 @@ plumbing and the implementation dispatch:
   impl="pallas"            compiled Pallas TPU kernel (the deploy target)
   impl="pallas_interpret"  Pallas kernel body interpreted on CPU (tests)
 
-Set the process-wide default with ``set_default_impl`` (e.g. launcher sets
-"pallas" on TPU backends).
+Set the process-wide default with ``set_default_impl``; the train launcher
+sets "pallas" on TPU backends. Under that default a shape-gate fallback is
+an error (``record_fallback``): on the chip a kernel is never silently
+replaced by the jnp path.
 """
 from __future__ import annotations
 
@@ -64,6 +66,11 @@ def record_dispatch(kernel: str, impl: str) -> None:
 
 
 def record_fallback(kernel: str, reason: str) -> None:
+    if _DEFAULT_IMPL == "pallas":
+        raise RuntimeError(
+            f"repro.kernels.ops: {kernel} cannot use the compiled Pallas "
+            f"kernel for this call shape (reason: {reason}); refusing the "
+            "jnp fallback under the compiled default")
     _DISPATCH_COUNTS[f"{kernel}/fallback/{reason}"] += 1
     key = (kernel, reason)
     if key not in _WARNED_FALLBACKS:
@@ -239,25 +246,19 @@ def matmul_fusable(shape: tuple[int, ...], block: int) -> bool:
 
 
 @functools.cache
-def _divisor_leq(n: int, cap: int) -> int:
-    """Largest divisor of n that is <= cap (>= 1)."""
-    for d in range(min(n, cap), 0, -1):
+def _tile(n: int, cap: int, unit: int) -> int:
+    """Block size for one dim of a *compiled* kernel: the largest multiple
+    of ``unit`` that divides ``n`` and is <= ``cap``, else ``n`` itself.
+
+    Mosaic takes a block whose last two dims are (8, 128)-aligned or span
+    the whole array, so ``unit`` is 8 for a sublane dim and 128 for a lane
+    dim. A lane dim that also carries per-block scales (scale tile
+    ``t // block`` wide) needs ``unit = 128 * block``, which at real widths
+    leaves the full extent."""
+    for d in range(min(n, cap) // unit * unit, 0, -unit):
         if n % d == 0:
             return d
-    return 1
-
-
-def _contraction_tile(c_len: int, block: int, transpose: bool) -> int:
-    """Contraction tile for the *compiled* kernel (one accumulation step per
-    tile). Along K (transpose=False) any divisor works; along N
-    (transpose=True) the tile must stay a whole number of scale blocks.
-    Capped near 512 so compiled tiles stay VMEM-sized.
-
-    The bitwise pair (jnp / pallas_interpret) does NOT use this: it uses
-    ``_loop_split`` so both legs lower to a real (>= 2 step) while loop."""
-    if transpose:
-        return block * _divisor_leq(c_len // block, max(1, 512 // block))
-    return _divisor_leq(c_len, 512)
+    return n
 
 
 @functools.cache
@@ -325,13 +326,15 @@ def dequant_matmul(x2, q_flat, scales, w_shape: tuple[int, int],
     else:
         # compiled TPU: VMEM-sized tiles (the fused win is HBM traffic, so
         # the accumulation order may differ from the CPU oracle here — like
-        # any other MXU-vs-CPU matmul)
-        bc = _contraction_tile(c_len, block, transpose)
-        bm = _divisor_leq(m_pad, 256)
+        # any other MXU-vs-CPU matmul). The N dim of q carries the scale
+        # tile, so it is 128*block-aligned or whole (see ``_tile``).
+        bm = _tile(m_pad, 256, 8)
         if transpose:
-            bo = _divisor_leq(out_dim, 512)
+            bc = _tile(c_len, 512, 128 * block)
+            bo = _tile(out_dim, 512, 128)
         else:
-            bo = block * _divisor_leq(out_dim // block, max(1, 512 // block))
+            bc = _tile(c_len, 512, 128)
+            bo = _tile(out_dim, 512, 128 * block)
 
         def run(x2, q2, s2):
             return dequant_matmul_flat_pallas(
@@ -514,11 +517,14 @@ def matmul_quant(x2, g2, block: int = DEFAULT_BLOCK, *, bits: int = 8,
             return matmul_quant_pallas(x2, g2, block=block, bits=bits,
                                        bk=kk, bn=n, bc=bc_pair, interpret=True)
     else:
-        bc = _divisor_leq(m, 512)
-        if bc < 8:
-            bc = m  # awkward M (prime-ish): one full-extent step
-        bk = _divisor_leq(kk, 256)
-        bn = block * _divisor_leq(n // block, max(1, 512 // block))
+        # the output's N dim carries the scale tile (and the nibble pairs):
+        # 128*block-aligned or whole, see ``_tile``. bk <= 128 keeps the
+        # INT4 pack scratch (bn, bk) within what a strided load takes; the
+        # g tile (bc, bn) is held near 2 MiB of f32 so the pipeline fits
+        # the default scoped VMEM.
+        bn = _tile(n, 512, 128 * block)
+        bk = _tile(kk, 128, 128)
+        bc = _tile(m, max(8, (1 << 19) // bn), 8)
 
         def run(x2, g2):
             return matmul_quant_pallas(x2, g2, block=block, bits=bits,

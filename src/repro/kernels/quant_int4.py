@@ -5,8 +5,11 @@ gradients"): FP16/FP32 gradient blocks are quantized to 4 bits, packed two
 nibbles per uint8, exchanged, and dequantized exactly once on the receiver.
 
 TPU note: there is no native int4 vector type on the VPU, so packing is done
-with uint8 integer arithmetic on even/odd element pairs. The (nb, bs) tile is
-viewed as (..., bs//2, 2); low nibble = even element, high nibble = odd.
+with integer arithmetic on even/odd element pairs: low nibble = even element,
+high nibble = odd. Mosaic can neither split the lane dim into (bs//2, 2) nor
+load with a lane stride, so ``pack_nibbles`` transposes the codes into a VMEM
+scratch (pairs on adjacent sublanes), reads the even and odd rows with
+sublane-strided loads and transposes the packed bytes back.
 """
 from __future__ import annotations
 
@@ -16,20 +19,29 @@ import math
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 INT4_QMAX = 7.0
 ROWS_PER_TILE = 8
 
 
-def _quant_int4_kernel(x_ref, q_ref, s_ref):
+def pack_nibbles(codes, t_ref):
+    """(r, c) int32 codes in [0, 15] -> (r, c // 2) uint8, element 2i in the
+    low nibble and 2i+1 in the high one. ``t_ref``: (c, r) int32 VMEM
+    scratch (see the module note)."""
+    half = codes.shape[1] // 2
+    t_ref[...] = codes.T
+    lo = t_ref[pl.ds(0, half, stride=2), :]
+    hi = t_ref[pl.ds(1, half, stride=2), :]
+    return (lo | (hi << 4)).T.astype(jnp.uint8)
+
+
+def _quant_int4_kernel(x_ref, q_ref, s_ref, t_ref):
     x = x_ref[...].astype(jnp.float32)
     absmax = jnp.max(jnp.abs(x), axis=-1, keepdims=True)
     scale = jnp.where(absmax == 0.0, 1.0, absmax / INT4_QMAX)
     q = jnp.clip(jnp.round(x / scale), -INT4_QMAX, INT4_QMAX).astype(jnp.int32) + 8
-    r, c = x.shape
-    q = q.reshape(r, c // 2, 2)
-    packed = q[..., 0] | (q[..., 1] << 4)
-    q_ref[...] = packed.astype(jnp.uint8)
+    q_ref[...] = pack_nibbles(q, t_ref)
     s_ref[...] = scale
 
 
@@ -60,6 +72,7 @@ def quantize_int4_pallas(blocks: jnp.ndarray, *, interpret: bool = False):
             jax.ShapeDtypeStruct((nb, bs // 2), jnp.uint8),
             jax.ShapeDtypeStruct((nb, 1), jnp.float32),
         ],
+        scratch_shapes=[pltpu.VMEM((bs, rows), jnp.int32)],
         interpret=interpret,
     )(blocks)
 

@@ -25,6 +25,7 @@ from __future__ import annotations
 
 import os
 from dataclasses import dataclass
+from pathlib import Path
 
 
 @dataclass(frozen=True)
@@ -152,11 +153,7 @@ def initialize(dcfg: DistConfig | None = None, *,
     # instantiate the runtime before jax.distributed gets to. Select gloo
     # unconditionally: it only affects the CPU client, and a CPU cluster
     # without it forms fine but deadlocks on the first collective.
-    if not enable_cpu_collectives() and _looks_like_cpu():
-        raise RuntimeError(
-            "this JAX version has no cross-process CPU collectives backend "
-            "(jax_cpu_collectives_implementation); multi-process CPU runs "
-            "need a newer jax")
+    enable_cpu_collectives()
     jax.distributed.initialize(coordinator_address=dcfg.coordinator,
                                num_processes=dcfg.num_processes,
                                process_id=dcfg.process_id)
@@ -191,11 +188,27 @@ def _force_local_devices(n: int, dcfg: DistConfig) -> None:
             f"or set --xla_force_host_platform_device_count={n}.")
 
 
-def _looks_like_cpu() -> bool:
-    """Env-only CPU heuristic (safe to evaluate pre-initialize)."""
-    return bool(os.environ.get("JAX_PLATFORMS", "").startswith("cpu")
-                or "xla_force_host_platform_device_count"
-                in os.environ.get("XLA_FLAGS", ""))
+def enable_compile_cache() -> str | None:
+    """Turn on JAX's persistent compilation cache for an accelerator run;
+    returns its directory. Call after ``initialize`` and before the first
+    compile.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX already caches there and
+    nothing is set here. Otherwise the cache lives at
+    ``<checkout>/.jax_cache``: a fixed path, because the path is part of
+    the cache key and a directory that moves never hits. On the CPU backend
+    no cache is set: its compiles take seconds, and XLA:CPU warns about the
+    host's machine features whenever it loads a cached entry.
+    """
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    import jax
+    if jax.default_backend() == "cpu":
+        return None
+    path = str(Path(__file__).resolve().parents[3] / ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
 
 
 def process_count() -> int:

@@ -200,8 +200,6 @@ def run_combo(arch_name, shape_name, mesh_name, scheme, outdir: Path,
 
     mem = compiled.memory_analysis()
     cost = compiled.cost_analysis() or {}
-    if isinstance(cost, (list, tuple)):   # jax<=0.4.x returns [dict]
-        cost = cost[0] if cost else {}
     txt = compiled.as_text()
     census = hlo.analyze(txt).summary()
 

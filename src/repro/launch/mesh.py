@@ -16,6 +16,10 @@ Axis-to-bandwidth-tier mapping (DESIGN.md §2):
       "gcd" (2)        = the MI250X GCD pair       -> primary weight shards
       "node"x"gcd" (8) = the Frontier node         -> gradient shards + secondary
       "data"x"repl"    = inter-node                -> optimizer shards
+
+  device mesh (data, node, gcd) over the live devices: 1 chip -> (1, 1, 1),
+      a 2x2 host -> (1, 2, 2) with each gcd pair two ICI neighbours, 8 (fake
+      CPU) devices -> (2, 2, 2), the test mesh's shape.
 """
 from __future__ import annotations
 
@@ -32,6 +36,25 @@ def make_topo_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 2, 4, 2) if multi_pod else (16, 2, 4, 2)
     axes = (("pod",) if multi_pod else ()) + ("data", "repl", "node", "gcd")
     return _mk(shape, axes if multi_pod else ("data", "repl", "node", "gcd"))
+
+
+def make_device_mesh(devices=None):
+    """(data, node, gcd) mesh over ``devices`` (default: ``jax.devices()``).
+
+    ``gcd`` and ``node`` take a factor of 2 each where the count allows,
+    the rest goes to ``data``. Devices are ordered process-major, then by
+    their torus coordinates with x fastest, so on a TPU host each gcd pair
+    is two chips joined by a direct ICI link and ``zero_tiers`` maps it to
+    l0."""
+    import jax
+    devs = list(jax.devices() if devices is None else devices)
+    n = len(devs)
+    gcd = 2 if n % 2 == 0 else 1
+    node = 2 if n % 4 == 0 else 1
+    devs.sort(key=lambda d: (d.process_index,
+                             tuple(reversed(getattr(d, "coords", ()))), d.id))
+    return _mk((n // (node * gcd), node, gcd), ("data", "node", "gcd"),
+               devices=devs)
 
 
 def make_test_mesh(shape=(2, 2, 2), axes=("data", "node", "gcd")):

@@ -9,6 +9,9 @@ training engine's shards.
         --backend resident --requests 16 --devices 8
     PYTHONPATH=src python -m repro.launch.serve --n-pages 6 \
         --max-queue-steps 8 --requests 64        # oversubscribed + SLO
+
+CPU only for now: it forces fake CPU devices through XLA_FLAGS and serves
+the reduced model on the 8-device test mesh; it has not run on a TPU.
 """
 import argparse
 import os

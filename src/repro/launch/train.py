@@ -3,8 +3,12 @@
     PYTHONPATH=src python -m repro.launch.train --arch qwen2-0.5b \
         --scheme zero_topo --steps 100 --reduced --devices 8
 
-``--reduced`` trains the smoke-scale variant on fake CPU devices (what this
-container can run); on a real TPU pod drop it and pass --mesh prod.
+``--devices N`` runs on N fake CPU devices (the platform is pinned to the
+CPU); without it the run takes the live devices, e.g. one TPU chip or a
+2x2 host, and the mesh is built from them (``mesh.make_device_mesh``).
+``--reduced`` swaps in the smoke-scale variant of the architecture;
+without it the model trains at its published widths. On a TPU backend the
+compiled Pallas kernels are the default (``--kernel-impl`` overrides).
 
 Multi-process (one process per node/GCD; README "Multi-host quickstart"):
 either pass --coordinator/--num-processes/--process-id explicitly, or let
@@ -14,7 +18,8 @@ the *global* device count; each process brings its share.
 import argparse
 import os
 
-from .distributed import add_cli_args, from_args, initialize
+from .distributed import (add_cli_args, enable_compile_cache, from_args,
+                          initialize)
 
 
 def build_parser() -> argparse.ArgumentParser:
@@ -22,14 +27,19 @@ def build_parser() -> argparse.ArgumentParser:
     ``repro.launch.cli_reference``)."""
     ap = argparse.ArgumentParser(
         prog="python -m repro.launch.train",
-        description="training launcher (smoke-scale on fake CPU devices "
-                    "with --reduced, or a real pod)")
+        description="training launcher (the live devices, or N fake CPU "
+                    "devices with --devices N)")
     ap.add_argument("--arch", default="qwen2-0.5b")
     ap.add_argument("--scheme", default="zero_topo",
                     help="partition preset, or 'auto' to let the topology "
                          "planner (repro.topo) pick for the live mesh")
-    ap.add_argument("--mesh", default="test")
-    ap.add_argument("--devices", type=int, default=8)
+    ap.add_argument("--mesh", default="auto", choices=["auto", "prod", "topo"],
+                    help="auto: (data, node, gcd) over the live devices "
+                         "(1 -> 1x1x1, 4 -> 1x2x2, 8 -> 2x2x2); prod/topo: "
+                         "the 256/512-device pod meshes")
+    ap.add_argument("--devices", type=int, default=None,
+                    help="run on this many fake CPU devices (global count); "
+                         "omit to use the live devices")
     ap.add_argument("--steps", type=int, default=50)
     ap.add_argument("--batch", type=int, default=8)
     ap.add_argument("--seq", type=int, default=128)
@@ -47,8 +57,9 @@ def build_parser() -> argparse.ArgumentParser:
     ap.add_argument("--kernel-impl", default=None,
                     choices=["jnp", "pallas", "pallas_interpret"],
                     help="quantization-kernel implementation (DESIGN.md §5):"
-                         " jnp oracle (default), compiled Pallas (TPU), or"
-                         " interpreted Pallas bodies (CPU validation)")
+                         " jnp oracle (default off TPU), compiled Pallas "
+                         "(default on TPU), or interpreted Pallas bodies "
+                         "(CPU validation)")
     ap.add_argument("--compute-dtype", default=None,
                     choices=["bfloat16", "float32"],
                     help="activation/primary dtype (default: the scheme's, "
@@ -96,46 +107,53 @@ def build_parser() -> argparse.ArgumentParser:
     return ap
 
 
-def main():
+def main(argv=None):
+    """Run the launcher on ``argv`` (default: the command line); returns
+    the ``Trainer`` (its ``log`` holds the per-step record) and the final
+    state."""
     ap = build_parser()
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
     if args.resume and not args.ckpt_dir:
         ap.error("--resume requires --ckpt-dir")
 
     dcfg = from_args(args)
-    n_fake = args.devices if args.mesh == "test" else 512
-    if n_fake % dcfg.num_processes:
-        ap.error(f"--devices {n_fake} not divisible by the "
-                 f"{dcfg.num_processes} processes ({dcfg.source})")
+    if args.devices:
+        if args.devices % dcfg.num_processes:
+            ap.error(f"--devices {args.devices} not divisible by the "
+                     f"{dcfg.num_processes} processes ({dcfg.source})")
+        # fake devices are CPU devices: this run never takes an accelerator
+        os.environ.setdefault("JAX_PLATFORMS", "cpu")
     # rendezvous (no-op single-process) BEFORE the first jax device access;
     # each process only forces its local share of the fake CPU devices
-    initialize(dcfg, local_devices=n_fake // dcfg.num_processes)
+    initialize(dcfg, local_devices=args.devices // dcfg.num_processes
+               if args.devices else None)
+    enable_compile_cache()
     log0 = print if dcfg.process_id == 0 else (lambda *a, **k: None)
 
     import jax
     if args.compute_dtype == "float32":
         jax.config.update("jax_default_matmul_precision", "float32")
-    if args.kernel_impl:
-        # process default: covers every config built from here on (the
-        # explicit per-config override below pins the engine's own cfg)
-        from ..kernels import ops as kernel_ops
-        kernel_ops.set_default_impl(args.kernel_impl)
+    # process default: covers every config built from here on (attention
+    # dispatches on it; the explicit per-config override below pins the
+    # engine's own cfg). On a TPU a shape-gate fallback is then an error.
+    from ..kernels import ops as kernel_ops
+    impl = args.kernel_impl or \
+        ("pallas" if jax.default_backend() == "tpu" else None)
+    if impl:
+        kernel_ops.set_default_impl(impl)
     from ..core.engine import TrainHparams, ZeroEngine
-    from ..models.config import ShapeConfig, SHAPES
+    from ..models.config import ShapeConfig
     from ..models.registry import build_model, get_arch
     from ..train.trainer import Trainer
-    from .mesh import make_production_mesh, make_test_mesh, make_topo_mesh, \
-        scheme_config
+    from .mesh import make_device_mesh, make_production_mesh, \
+        make_topo_mesh, scheme_config
 
-    mesh = {"test": lambda: make_test_mesh(),
-            "prod": lambda: make_production_mesh(),
-            "topo": lambda: make_topo_mesh()}[args.mesh]()
+    mesh = {"auto": make_device_mesh, "prod": make_production_mesh,
+            "topo": make_topo_mesh}[args.mesh]()
     arch = get_arch(args.arch)
-    if args.reduced or args.mesh == "test":
+    if args.reduced:
         arch = arch.reduced()
-        shape = ShapeConfig("cli", args.seq, args.batch, "train")
-    else:
-        shape = SHAPES["train_4k"]
+    shape = ShapeConfig("cli", args.seq, args.batch, "train")
 
     model = build_model(arch)
     planner_kw = {}
@@ -148,7 +166,7 @@ def main():
         if args.compute_dtype else {}
     cfg = scheme_config(args.scheme, mesh, quant_block=args.quant_block,
                         overlap=args.overlap, stream_grads=args.stream_grads,
-                        impl=args.kernel_impl, **dtype_kw, **planner_kw)
+                        impl=impl, **dtype_kw, **planner_kw)
     if args.scheme == "auto":
         a = cfg.axes
         log0(f"planner choice: w={a.weight} e={a.extra_grad} r={a.replica} "
@@ -199,6 +217,7 @@ def main():
              f"{agg['tokens_per_s_mean']:.0f} tok/s, "
              f"{agg['tflops_per_gpu_mean']:.3f} model-TFLOPS/GPU")
     log0(f"final loss: {tr.log.losses[-1]}")
+    return tr, state
 
 
 if __name__ == "__main__":

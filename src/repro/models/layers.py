@@ -6,7 +6,8 @@ dispatch (``jnp | pallas | pallas_interpret``, inherited from
 ``ops.attention_fusable`` decides whether a call shape can use the Pallas
 kernel path, and rejected shapes (MLA value dims, traced decode offsets,
 unaligned seqs) fall back to the chunked jnp scan below — with a one-time
-structured warning and a dispatch-counter record, never silently.
+structured warning and a dispatch-counter record, never silently; under the
+compiled "pallas" default (TPU) such a shape is an error instead.
 
 ``flash_decode`` is the sequence-sharded single-token decode attention used
 for 32k/500k KV caches: each device computes a partial softmax over its local

@@ -179,11 +179,7 @@ def memory_high_water() -> int:
     import jax
     peak = 0
     for d in jax.local_devices():
-        stats = getattr(d, "memory_stats", None)
-        try:
-            ms = stats() if stats else None
-        except Exception:
-            ms = None
+        ms = d.memory_stats()
         if ms:
             peak = max(peak, int(ms.get("peak_bytes_in_use",
                                         ms.get("bytes_in_use", 0))))

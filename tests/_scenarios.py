@@ -74,6 +74,20 @@ def collectives():
 
     assert metric(secondary_rebuild_err, x).max() == 0.0
 
+    def wire_a2a_vs_plain(shard):
+        """The wire all-to-all exchanges lane-shaped rows; the bytes each
+        member receives must be exactly the plain all-to-all's."""
+        out = []
+        for dtype in (jnp.int8, jnp.uint8):
+            q = (shard * 40).astype(jnp.int32).astype(dtype).reshape(8, -1)
+            plain = lax.all_to_all(q, AX, 0, 0, tiled=False)
+            wire = col._wire_all_to_all(q, AX)
+            out.append(jnp.max(jnp.abs(plain.astype(jnp.int32)
+                                       - wire.astype(jnp.int32))))
+        return jnp.max(jnp.stack(out)).astype(jnp.float32)
+
+    assert metric(wire_a2a_vs_plain, x).max() == 0.0
+
     y = jax.random.normal(jax.random.key(1), (2048 * 8,))
 
     def rs4_abs_over_bound(shard):

@@ -354,3 +354,58 @@ else:
     def test_prop_hypothesis_missing():
         pytest.skip("hypothesis not installed (optional [test] extra); "
                     "property tests run on CI")
+
+
+def _interpret_compiled_tiles(monkeypatch):
+    """Run ``impl="pallas"`` — the compiled path's tile choice and grid —
+    through the Pallas interpreter, so its index maps are checked for
+    numbers on CPU."""
+    for name in ("dequant_matmul_flat_pallas", "matmul_quant_pallas"):
+        real = getattr(ops, name)
+        monkeypatch.setattr(
+            ops, name, lambda *a, real=real, **k: real(*a, **{
+                **k, "interpret": True}))
+
+
+@pytest.mark.parametrize("transpose", [False, True])
+@pytest.mark.parametrize("k,n,block", [(1024, 256, 128), (256, 1024, 2)])
+def test_compiled_dequant_matmul_tiles_match_ref(monkeypatch, k, n, block,
+                                                 transpose):
+    """(1024, 256, 128): several contraction / output / row tiles with the
+    scale tile spanning N; (256, 1024, 2): two 128*block-wide N tiles."""
+    _interpret_compiled_tiles(monkeypatch)
+    m = 520
+    q = jax.random.randint(jax.random.key(1), (k * n,), -127, 128,
+                           jnp.int32).astype(jnp.int8)
+    s = jax.random.uniform(jax.random.key(2), (k * n // block,),
+                           jnp.float32, 0.5, 1.5)
+    x = _rand((m, n if transpose else k), jnp.float32, 3)
+    out = ops.dequant_matmul(x, q, s, (k, n), block, transpose=transpose,
+                             dtype=jnp.float32, impl="pallas")
+    w = ref.dequant_w_flat_ref(q.reshape(k, n), s.reshape(k, n // block),
+                               block)
+    want = x @ (w.T if transpose else w)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(want),
+                               rtol=1e-5, atol=1e-5 * float(
+                                   jnp.max(jnp.abs(want))))
+
+
+@pytest.mark.parametrize("bits", [8, 4])
+def test_compiled_matmul_quant_tiles_match_ref(monkeypatch, bits):
+    """M 4096 gives two contraction steps, K 256 two output row tiles; every
+    element must land within half a quantization step of x.T @ g."""
+    _interpret_compiled_tiles(monkeypatch)
+    m, k, n, block = 4096, 256, 256, 128
+    x = _rand((m, k), jnp.float32, 4)
+    g = _rand((m, n), jnp.float32, 5)
+    q, s = ops.matmul_quant(x, g, block, bits=bits, impl="pallas")
+    if bits == 4:
+        p = np.asarray(q, np.int32)
+        codes = np.stack([p & 0xF, p >> 4], axis=-1).reshape(-1) - 8
+    else:
+        codes = np.asarray(q, np.int32)
+    s = np.asarray(s)
+    deq = (codes.reshape(-1, block) * s[:, None]).reshape(k, n)
+    want = np.asarray(x).T.astype(np.float64) @ np.asarray(g, np.float64)
+    step = np.repeat(s, block).reshape(k, n)
+    assert (np.abs(deq - want) <= 0.5 * step * (1 + 1e-4) + 1e-4).all()

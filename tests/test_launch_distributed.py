@@ -1,8 +1,11 @@
 """launch.distributed config resolution — pure env/flag logic, no cluster.
 The live rendezvous paths are covered by tests/test_multiprocess.py."""
+from pathlib import Path
+
+import jax
 import pytest
 
-from repro.launch.distributed import DistConfig, detect
+from repro.launch.distributed import DistConfig, detect, enable_compile_cache
 
 
 def test_explicit_flags_win(monkeypatch):
@@ -68,3 +71,24 @@ def test_cli_args_roundtrip():
     d = from_args(args)
     assert d == DistConfig("h:1", 2, 1, "flags")
     assert not from_args(ap.parse_args([])).is_distributed
+
+
+def test_compile_cache_dir(monkeypatch):
+    """JAX_COMPILATION_CACHE_DIR wins untouched; otherwise one fixed,
+    git-ignored directory inside the checkout on an accelerator, and no
+    cache on the CPU backend."""
+    before = jax.config.jax_compilation_cache_dir
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere")
+    assert enable_compile_cache() == "/elsewhere"
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    assert enable_compile_cache() is None
+    assert jax.config.jax_compilation_cache_dir == before
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    try:
+        path = enable_compile_cache()
+        assert jax.config.jax_compilation_cache_dir == path
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
+    root = Path(__file__).resolve().parents[1]
+    assert path == str(root / ".jax_cache")
+    assert ".jax_cache/" in (root / ".gitignore").read_text().split()
