@@ -33,6 +33,11 @@ The train step (inside one ``shard_map`` over the full mesh):
      INT8-quantized (beyond-paper); stacked leaves gather their last axis in
      one batched collective.
 
+Each phase runs under its ``obs.spans.SEGMENTS`` named scope (``fwd_bwd``,
+``grad_rs_e``, ``cross_replica``, ``gnorm_clip``, ``update``), so a profiler
+trace of the fused step splits by phase through each op's ``op_name``; the
+backward shows there as ``transpose(jvp(...))`` inside ``fwd_bwd``.
+
 ``check_vma=False``: the engine manages replication manually — automatic
 psum-insertion on replicated-input cotangents would defeat the paper's
 deferred hierarchical gradient sync.
@@ -52,6 +57,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..analysis.tags import tag as _contract_tag
 from ..compat import shard_map
+from ..obs import spans
 from . import collectives as col
 from . import schedule as sched
 from .linear import (make_gather_issue, make_plain_gather, make_zero_gather_q,
@@ -544,18 +550,20 @@ class ZeroEngine:
         over weight+extra-grad axes but still device-varying over the
         replica axes — stage 3 below completes the sync."""
         lcfg = self.leaf_cfg[name]
-        g = g.astype(jnp.float32)
-        flat = g.reshape(-1, g.shape[-1]) if g.ndim > 1 else g[None]
-        out = jax.vmap(lambda row: col.reduce_scatter_flat(
-            row, lcfg.axes.extra_grad, lcfg))(flat)
-        return out if g.ndim > 1 else out[0]
+        with spans.scope("grad_rs_e"):
+            g = g.astype(jnp.float32)
+            flat = g.reshape(-1, g.shape[-1]) if g.ndim > 1 else g[None]
+            out = jax.vmap(lambda row: col.reduce_scatter_flat(
+                row, lcfg.axes.extra_grad, lcfg))(flat)
+            return out if g.ndim > 1 else out[0]
 
     def _replica_sync(self, name: str, g):
         """Stage 3: cross-replica sync of a stage-2-scattered grad."""
         lcfg = self.leaf_cfg[name]
-        flat = g.reshape(-1, g.shape[-1]) if g.ndim > 1 else g[None]
-        out = jax.vmap(lambda row: col.cross_replica_grad(row, lcfg))(flat)
-        return out if g.ndim > 1 else out[0]
+        with spans.scope("cross_replica"):
+            flat = g.reshape(-1, g.shape[-1]) if g.ndim > 1 else g[None]
+            out = jax.vmap(lambda row: col.cross_replica_grad(row, lcfg))(flat)
+            return out if g.ndim > 1 else out[0]
 
     def _to_os(self, name: str, g):
         """Stage 2 + 3 for a primary-layout grad (seed path; streamed
@@ -582,20 +590,22 @@ class ZeroEngine:
         movement, bitwise-identical values)."""
         from ..optim.adamw import adamw_update
         cfg, hp = self.cfg, self.hp
-        step = state["step"] + 1
-        lr = self._lr(state["step"])
         b1, b2 = hp.betas
         cdt = jnp.dtype(cfg.compute_dtype)
         new_m, new_v, new_master, new_prim = {}, {}, {}, {}
-        for n in sorted(self.specs):
-            wd = hp.weight_decay \
-                if self.specs[n].kind in (MATMUL, GATHER_Q) else 0.0
-            master, m, v = adamw_update(
-                state["master"][n], state["opt_m"][n], state["opt_v"][n],
-                os_grads[n], step=step, lr=lr, beta1=b1, beta2=b2,
-                eps=hp.eps, weight_decay=wd)
-            new_m[n], new_v[n], new_master[n] = m, v, master
-            new_prim[n] = col.update_all_gather(master, self.leaf_cfg[n], cdt)
+        with spans.scope("update"):
+            step = state["step"] + 1
+            lr = self._lr(state["step"])
+            for n in sorted(self.specs):
+                wd = hp.weight_decay \
+                    if self.specs[n].kind in (MATMUL, GATHER_Q) else 0.0
+                master, m, v = adamw_update(
+                    state["master"][n], state["opt_m"][n], state["opt_v"][n],
+                    os_grads[n], step=step, lr=lr, beta1=b1, beta2=b2,
+                    eps=hp.eps, weight_decay=wd)
+                new_m[n], new_v[n], new_master[n] = m, v, master
+                new_prim[n] = col.update_all_gather(master, self.leaf_cfg[n],
+                                                    cdt)
         return dict(primaries=new_prim, master=new_master,
                     opt_m=new_m, opt_v=new_v, step=step), lr
 
@@ -625,7 +635,8 @@ class ZeroEngine:
         local_grads = self._make_local_grads(loss_fn)
 
         def local_step(state, batch):
-            grads, loss_rep, gtok = local_grads(state["primaries"], batch)
+            with spans.scope("fwd_bwd"):
+                grads, loss_rep, gtok = local_grads(state["primaries"], batch)
 
             g_legacy, g_sinks = grads if stream else (grads, {})
             os_grads = self._grads_to_os(g_legacy, g_sinks)
@@ -721,10 +732,11 @@ class ZeroEngine:
         det_psum: gnorm feeds the clip scale applied to every gradient, so
         a transport-dependent reduction order here would make the entire
         update drift across process layouts."""
-        sq = sum(jnp.sum(jnp.square(g)) for g in os_grads.values())
-        gnorm = jnp.sqrt(col.det_psum(sq, self.cfg.axes.all))
-        scale = jnp.minimum(1.0, self.hp.grad_clip / (gnorm + 1e-6))
-        return {n: g * scale for n, g in os_grads.items()}, gnorm
+        with spans.scope("gnorm_clip"):
+            sq = sum(jnp.sum(jnp.square(g)) for g in os_grads.values())
+            gnorm = jnp.sqrt(col.det_psum(sq, self.cfg.axes.all))
+            scale = jnp.minimum(1.0, self.hp.grad_clip / (gnorm + 1e-6))
+            return {n: g * scale for n, g in os_grads.items()}, gnorm
 
     def _finish_step(self, state, os_grads: dict, loss_rep, gtok):
         """Post-reduction tail of the train step (local, inside shard_map):

@@ -83,8 +83,7 @@ def issue_buffers(fns, primaries, names):
     in particular no transposed collective — flows back through the scan
     carry (see module docstring).
     """
-    # obs scope: names this issue site in profiler traces under --trace;
-    # a nullcontext otherwise (spans.scope is dead by default, like _tag)
+    # obs scope: names this issue site in the op_name of every op it emits
     with _spans.scope("gather/issue"):
         return {n: fns[n].issue(lax.stop_gradient(primaries[n]))
                 for n in names}

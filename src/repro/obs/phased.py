@@ -35,7 +35,7 @@ from jax.sharding import PartitionSpec as P
 from ..compat import shard_map
 from ..core import collectives as col
 from ..core.partition import GATHER_Q, MATMUL
-from .spans import PROBES, SpanRecorder, tracing
+from .spans import PROBES, SpanRecorder
 
 
 class PhasedStep:
@@ -106,17 +106,15 @@ class PhasedStep:
 
     def __call__(self, state, batch, rec: SpanRecorder):
         """One fenced step; same (new_state, metrics) as the seed step."""
-        with tracing():
-            g_leg, g_sink, loss_rep, gtok = rec.fenced(
-                "fwd_bwd", self._grads, state, batch)
-            if self.legacy:
-                g_leg = rec.fenced("grad_rs_e", self._stage2, g_leg)
-                g_leg = rec.fenced("cross_replica", self._cross, g_leg)
-            os_grads = {n: g_leg[n] if n in g_leg else g_sink[n]
-                        for n in self.names}
-            os_grads, gnorm = rec.fenced("gnorm_clip", self._clip, os_grads)
-            new_state, lr = rec.fenced("update", self._update,
-                                       state, os_grads)
+        g_leg, g_sink, loss_rep, gtok = rec.fenced(
+            "fwd_bwd", self._grads, state, batch)
+        if self.legacy:
+            g_leg = rec.fenced("grad_rs_e", self._stage2, g_leg)
+            g_leg = rec.fenced("cross_replica", self._cross, g_leg)
+        os_grads = {n: g_leg[n] if n in g_leg else g_sink[n]
+                    for n in self.names}
+        os_grads, gnorm = rec.fenced("gnorm_clip", self._clip, os_grads)
+        new_state, lr = rec.fenced("update", self._update, state, os_grads)
         metrics = dict(loss=loss_rep, grad_norm=gnorm, lr=lr, tokens=gtok)
         return new_state, metrics
 
@@ -228,18 +226,17 @@ class PhasedStep:
         collective family, fenced individually. Records one span per probe
         (NOT summed into the wall-time budget)."""
         prim = state["primaries"]
-        with tracing():
-            rec.fenced("fwd", self._eval, state, batch)
-            if self._p_fwd_ag is not None:
-                rec.fenced("fwd_allgather", self._p_fwd_ag,
-                           {n: prim[n] for n in self.pf})
-            if self._p_bwd_ag is not None:
-                rec.fenced("bwd_allgather", self._p_bwd_ag,
-                           {n: prim[n] for n in self.rs_leaves})
-            if self._p_grs_w is not None:
-                rec.fenced("grad_rs_w", self._p_grs_w,
-                           {n: prim[n] for n in self.rs_leaves})
-            rec.fenced("update_gather", self._p_upd, state["master"])
+        rec.fenced("fwd", self._eval, state, batch)
+        if self._p_fwd_ag is not None:
+            rec.fenced("fwd_allgather", self._p_fwd_ag,
+                       {n: prim[n] for n in self.pf})
+        if self._p_bwd_ag is not None:
+            rec.fenced("bwd_allgather", self._p_bwd_ag,
+                       {n: prim[n] for n in self.rs_leaves})
+        if self._p_grs_w is not None:
+            rec.fenced("grad_rs_w", self._p_grs_w,
+                       {n: prim[n] for n in self.rs_leaves})
+        rec.fenced("update_gather", self._p_upd, state["master"])
 
     def probe_inventory(self) -> dict:
         """Deterministic description of what the probes execute — gated in

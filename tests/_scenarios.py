@@ -907,6 +907,59 @@ def obs_trace_equivalence():
     print("SCENARIO_OK obs_trace_equivalence")
 
 
+def obs_step_scopes():
+    """Every phase of the monolithic step (obs.spans.SEGMENTS) names its ops
+    on the 8-device topo mesh, where the stage-2 reduce-scatter and the
+    cross-replica sync are real collectives; and the scopes change no
+    number: 2 steps give bitwise the losses, grad norms and master shards
+    of the same step built with every scope a null context."""
+    import contextlib
+    import re
+
+    from repro.core.engine import TrainHparams, ZeroEngine
+    from repro.models.registry import build_model, get_arch
+    from repro.obs import spans
+
+    jax.config.update("jax_default_matmul_precision", "float32")
+    mesh = _mesh()
+    arch = get_arch("qwen2-0.5b").reduced(n_layers=2, d_model=128, vocab=256)
+    model = build_model(arch)
+    cfg = _cfg("zero_topo", mesh, compute_dtype="float32")
+    rng = np.random.default_rng(0)
+    batch = {"tokens": jax.device_put(
+        jnp.asarray(rng.integers(0, arch.vocab, (8, 33), dtype=np.int32)),
+        NamedSharding(mesh, P(AX)))}
+
+    def run():
+        eng = ZeroEngine(model.leaf_specs(), cfg, mesh,
+                         TrainHparams(lr=1e-3, total_steps=8, warmup_steps=0))
+        step = eng.make_train_step(model.loss_fn(), {"tokens": P(AX)})
+        state = eng.init_state(jax.random.key(0))
+        hlo = step.lower(state, batch).compile().as_text()
+        out = []
+        for _ in range(2):
+            state, m = step(state, batch)
+            out.append((float(m["loss"]), float(m["grad_norm"])))
+        master = {n: np.asarray(state["master"][n].addressable_data(0))
+                  for n in sorted(eng.specs)}
+        return hlo, out, master
+
+    hlo, ms, master = run()
+    parts = {p for n in re.findall(r'op_name="([^"]*)"', hlo)
+             for p in n.split("/")}
+    assert set(spans.SEGMENTS) <= parts, set(spans.SEGMENTS) - parts
+    real = spans.scope
+    spans.scope = lambda name: contextlib.nullcontext()
+    try:
+        _, ms0, master0 = run()
+    finally:
+        spans.scope = real
+    assert ms == ms0, (ms, ms0)
+    for n in master:
+        np.testing.assert_array_equal(master[n], master0[n], err_msg=n)
+    print("SCENARIO_OK obs_step_scopes")
+
+
 def reshard_roundtrip():
     """Property test (DESIGN.md §11): random mesh-A -> mesh-B -> mesh-A
     reshard roundtrips are lossless — every state leaf sha256-identical to
@@ -999,6 +1052,7 @@ def reshard_roundtrip():
 SCENARIOS = dict(collectives=collectives,
                  reshard_roundtrip=reshard_roundtrip,
                  obs_trace_equivalence=obs_trace_equivalence,
+                 obs_step_scopes=obs_step_scopes,
                  collectives_split=collectives_split,
                  overlap_equivalence=overlap_equivalence,
                  stream_grads_equivalence=stream_grads_equivalence,

@@ -15,7 +15,7 @@ SCENARIOS = ["collectives", "reshard_roundtrip",
              "dp_vs_single", "serve_sharded",
              "hlo_census_real", "multipod_mesh", "resident_and_sp",
              "serve_resident_quant_equivalence",
-             "obs_trace_equivalence"]
+             "obs_trace_equivalence", "obs_step_scopes"]
 
 
 @pytest.mark.parametrize("name", SCENARIOS)

@@ -132,19 +132,199 @@ def test_trainlog_aggregates_exclude_compile_step():
 
 # -- spans -------------------------------------------------------------------
 
-def test_spans_dead_by_default():
-    """No tracing context => scope() is a null context and nothing in the
-    module is active — the discipline that keeps production jaxprs (and the
-    bitwise CI contracts) byte-identical to a build without obs."""
+def _tiny_trainer(seq=16, batch=2, data=None):
+    import jax
+
+    from repro.core.engine import TrainHparams, ZeroEngine
+    from repro.launch.mesh import make_test_mesh, scheme_config
+    from repro.models.config import ShapeConfig
+    from repro.models.registry import build_model, get_arch
+    from repro.train.trainer import Trainer
+    mesh = make_test_mesh(shape=(1, 1, 1), axes=("data", "node", "gcd"))
+    arch = get_arch("qwen2-0.5b").reduced(n_layers=2, d_model=64, vocab=128)
+    model = build_model(arch)
+    cfg = scheme_config("zero_topo", mesh, quant_block=64,
+                        compute_dtype="float32")
+    eng = ZeroEngine(model.leaf_specs(), cfg, mesh,
+                     TrainHparams(lr=1e-3, total_steps=8, warmup_steps=0))
+    tr = Trainer(model, eng, mesh, ShapeConfig("t", seq, batch, "train"),
+                 data=data)
+    return tr, eng.init_state(jax.random.key(0))
+
+
+def _op_names(hlo: str) -> list[str]:
+    import re
+    return re.findall(r'op_name="([^"]*)"', hlo)
+
+
+def test_step_scopes_change_metadata_only(monkeypatch):
+    """The step's phase scopes reach every op's ``op_name`` (the backward
+    as ``transpose(...)`` inside ``fwd_bwd``), and change nothing else: the
+    compiled program without its metadata is the same text, and two steps
+    give bitwise the same losses and master weights as a step built with
+    every scope a null context."""
     import contextlib
-    assert not spans.enabled()
-    assert isinstance(spans.scope("gather/issue"), contextlib.nullcontext)
-    with spans.tracing():
-        assert spans.enabled()
-        with spans.tracing():           # re-entrant
-            assert spans.enabled()
-        assert spans.enabled()          # inner exit must not disable outer
-    assert not spans.enabled()
+    import re
+
+    import numpy as np
+
+    def build_and_run():
+        tr, state = _tiny_trainer()
+        batch = tr._shard_batch(tr.data.batch(0))
+        hlo = tr.step_fn.lower(state, batch).compile().as_text()
+        losses = []
+        for _ in range(2):
+            state, m = tr.step_fn(state, batch)
+            losses.append(float(m["loss"]))
+        master = {n: np.asarray(v) for n, v in state["master"].items()}
+        return hlo, losses, master
+
+    hlo, losses, master = build_and_run()
+    with monkeypatch.context() as mp:
+        mp.setattr(spans, "scope", lambda name: contextlib.nullcontext())
+        hlo0, losses0, master0 = build_and_run()
+    names = _op_names(hlo)
+    parts = {p for n in names for p in n.split("/")}
+    assert {"fwd_bwd", "gnorm_clip", "update"} <= parts
+    fwd_bwd = [n.split("/") for n in names if "fwd_bwd" in n.split("/")]
+    assert any(any(p.startswith("transpose(") for p in n) for n in fwd_bwd)
+    assert any(not any(p.startswith("transpose(") for p in n) for n in fwd_bwd)
+    assert not {"fwd_bwd", "gnorm_clip", "update"} & {
+        p for n in _op_names(hlo0) for p in n.split("/")}
+
+    def strip(text):            # metadata, and the source-location tables
+        text = re.sub(r"^(FileNames|FunctionNames|FileLocations|StackFrames)"
+                      r"\n.*?\n\n", "", text, flags=re.S | re.M)
+        return re.sub(r", metadata=\{[^}]*\}", "", text)
+    assert strip(hlo) == strip(hlo0)
+    assert losses == losses0
+    for n in master:
+        np.testing.assert_array_equal(master[n], master0[n], err_msg=n)
+
+
+def test_trainer_spans_in_a_profiler_trace(tmp_path):
+    """Under ``jax.profiler.trace`` every loop phase of ``Trainer.run`` is a
+    ``train.*`` span nested in its step's ``train`` step marker, all
+    carrying the step number that ``TrainLog.steps`` records."""
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+
+    tr, state = _tiny_trainer()
+    state = tr.run(state, 1, log_every=0)        # compile outside the trace
+    with jax.profiler.trace(str(tmp_path / "tr")):
+        tr.run(state, 2, log_every=1, ckpt_dir=str(tmp_path / "ck"),
+               ckpt_every=2, print_fn=lambda *a: None)
+    path = glob.glob(str(tmp_path / "tr" / "plugins" / "profile" / "*"
+                         / "*.xplane.pb"))[0]
+    steps, kids = {}, []
+    for plane in ProfileData.from_file(path).planes:
+        for line in plane.lines:
+            for e in line.events:
+                st = dict(e.stats)
+                if e.name == "train":
+                    steps[st["step_num"]] = (e.start_ns, e.end_ns)
+                elif e.name.startswith("train."):
+                    kids.append((e.name, st["step"], e.start_ns, e.end_ns))
+    assert sorted(steps) == tr.log.steps[1:] == [2, 3]
+    loop = ("train.data", "train.shard", "train.dispatch", "train.wait",
+            "train.fetch", "train.log")
+    for k, (lo, hi) in steps.items():
+        mine = [(n, s, e) for n, step, s, e in kids if step == k]
+        assert all(lo <= s <= e <= hi for _, s, e in mine)
+        names = [n for n, _, _ in sorted(mine, key=lambda m: m[1])]
+        assert names == list(loop) + (["train.ckpt"] if k == 3 else [])
+
+
+def test_compile_counter_per_step():
+    """A run's first step compiles the step and its second compiles nothing;
+    a batch of a new shape recompiles, and the loop says so in one line."""
+    import re
+
+    import numpy as np
+
+    rng = np.random.default_rng(0)
+    batches = [{"tokens": rng.integers(0, 128, (2, 17), dtype=np.int32)}
+               for _ in range(2)]
+    batches.append({"tokens": rng.integers(0, 128, (2, 33), dtype=np.int32)})
+    tr, state = _tiny_trainer(data=batches)
+    said = []
+    tr.run(state, 3, log_every=0, print_fn=said.append)
+    assert tr.log.compiles[0] >= 1 and tr.log.compile_s[0] > 0
+    assert tr.log.compiles[1] == 0
+    assert tr.log.compiles[2] >= 1
+    assert len(said) == 1 and re.fullmatch(
+        rf"step 3: recompiled \({tr.log.compiles[2]} compilations; persistent "
+        rf"cache: \d+ hits, \d+ misses; {tr.log.compile_s[2]:.2f} s of "
+        rf"tracing, lowering and compiling\)", said[0]), said
+
+
+_CACHED_BUILDS = """
+import contextlib, sys
+import jax
+jax.config.update("jax_compilation_cache_dir", sys.argv[1])
+jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+sys.path.insert(0, "tests")
+import test_obs
+from repro.obs import spans
+
+def compiled():
+    tr, state = test_obs._tiny_trainer()
+    batch = tr._shard_batch(tr.data.batch(0))
+    return tr.step_fn.lower(state, batch).compile().as_text()
+
+real = spans.scope
+spans.scope = lambda name: contextlib.nullcontext()
+assert "fwd_bwd" not in compiled()
+spans.scope = real
+misses = spans.compile_counter().cache_misses
+assert "fwd_bwd" in compiled()
+assert spans.compile_counter().cache_misses > misses
+print("SCOPES_KEPT")
+"""
+
+
+def test_persistent_cache_keeps_the_steps_scopes(tmp_path):
+    """A step whose scopes differ from a cached build's is compiled afresh,
+    not served the cached executable with the other build's ``op_name``
+    metadata (JAX leaves metadata out of the cache key unless told)."""
+    import os
+    import subprocess
+    import sys
+    from pathlib import Path
+
+    root = Path(__file__).resolve().parents[1]
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               PYTHONPATH=os.pathsep.join(filter(None, (
+                   str(root / "src"), os.environ.get("PYTHONPATH")))))
+    out = subprocess.run(
+        [sys.executable, "-c", _CACHED_BUILDS, str(tmp_path / "cache")],
+        cwd=root, env=env, capture_output=True, text=True, timeout=300)
+    assert "SCOPES_KEPT" in out.stdout, out.stdout + out.stderr
+
+
+def test_compile_counter_counts_nested_spans_once():
+    """Compile time is the union of JAX's compile spans: a trace nested in
+    another's counts once, disjoint spans add, in any order of arrival;
+    executables are counted at the backend compile (or cache fetch)."""
+    c = spans.CompileCounter()
+    trace, lower, backend = spans.COMPILE_EVENTS
+    c._span(trace, 2.0, 5.0)          # an inner jit's trace ends first
+    c._span(trace, 0.0, 10.0)         # then its outer trace
+    c._span(lower, 10.0, 12.0)        # touches the trace: one span 0-12
+    c._span(backend, 20.0, 25.0)
+    c._span(backend, 14.0, 15.0)      # arrives late, lies before the last
+    c._span("/jax/other", 0.0, 100.0)
+    assert c.seconds == pytest.approx(12.0 + 5.0 + 1.0)
+    assert c._spans == [(0.0, 12.0), (14.0, 15.0), (20.0, 25.0)]
+    assert c.count == 2
+    c._event(spans.CACHE_HITS)
+    c._event(spans.CACHE_MISSES)
+    c._event(spans.CACHE_MISSES)
+    assert (c.cache_hits, c.cache_misses) == (1, 2)
+    assert spans.compile_counter() is spans.compile_counter()
 
 
 def test_span_recorder_and_chrome_export(tmp_path):
