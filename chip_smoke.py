@@ -280,8 +280,9 @@ def _memory_stats() -> list:
 
 
 def _compiled_only(counters: dict) -> list:
-    """Dispatch entries that are not the compiled Pallas kernel."""
-    return sorted(k for k in counters if not k.endswith("/pallas"))
+    """Dispatch entries that are not the compiled Pallas kernel (its
+    ``kernel/pallas`` count or a breakdown of it, such as the tile)."""
+    return sorted(k for k in counters if k.split("/")[1] != "pallas")
 
 
 def train_one_chip() -> list:

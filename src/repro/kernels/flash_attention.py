@@ -12,8 +12,10 @@ k/v are repeated by the ops.py wrapper — on TPU the repeat is a broadcast
 the compiler keeps virtual). Grid: (BH, num_q_blocks, num_kv_blocks), KV
 innermost so scratch carries across it. Causal and sliding-window masking
 are applied from absolute positions (q_offset supports decode/sequence-
-parallel callers). MXU-aligned tiles: bq, bk multiples of 128 recommended;
-D padded to a lane multiple by the wrapper.
+parallel callers). Nothing pads D: each block takes the whole head size.
+The compiled tiles (bq, bk) come from ``ops.flash_tiles``. The k/v index
+maps clamp to the kv blocks a q block needs, so a step that the mask skips
+repeats the previous block index and the pipeline fetches nothing for it.
 """
 from __future__ import annotations
 
@@ -94,7 +96,8 @@ def flash_attention_pallas(q, k, v, *, causal: bool = True, window: int = 0,
                            bk: int = 128, interpret: bool = False):
     """q (BH, Sq, D); k, v (BH, Sk, D) -> (BH, Sq, D).
 
-    BH % bb == Sq % bq == Sk % bk == 0 (ops.py gates); D lane-aligned.
+    BH % bb == Sq % bq == Sk % bk == 0 (ops.py gates); a block takes the
+    whole head size D.
     ``bb`` blocks the folded batch*heads dim: compiled TPU runs bb=1 tiles
     with the multi-block online softmax; the interpret/bitwise configuration
     runs FULL extents (bb=BH, bq=Sq, bk=Sk, grid (1,1,1)) — with a single
@@ -108,17 +111,34 @@ def flash_attention_pallas(q, k, v, *, causal: bool = True, window: int = 0,
     assert bh % bb == 0 and sq % bq == 0 and sk % bk == 0, \
         (q.shape, k.shape, bb, bq, bk)
     scale = 1.0 / math.sqrt(d)
-    grid = (bh // bb, sq // bq, sk // bk)
+    kv_blocks = sk // bk
+    grid = (bh // bb, sq // bq, kv_blocks)
     kernel = functools.partial(
         _flash_kernel, scale=scale, causal=causal, window=window,
-        q_offset=q_offset, bq=bq, bk=bk, kv_steps=sk // bk)
+        q_offset=q_offset, bq=bq, bk=bk, kv_steps=kv_blocks)
+
+    def kv_index(b, i, j):
+        """Clamp kv block j to the blocks q block i attends to (the bounds
+        of the kernel's skip test): a skipped step names a block the
+        pipeline already holds, so it copies nothing."""
+        if kv_blocks == 1:
+            return b, j, 0              # nothing to clamp (the bitwise grid)
+        first_q = q_offset + i * bq
+        if causal:
+            j = jnp.minimum(j, jnp.clip((first_q + bq - 1) // bk, 0,
+                                        kv_blocks - 1))
+        if window:
+            j = jnp.maximum(j, jnp.clip((first_q - window + 1) // bk, 0,
+                                        kv_blocks - 1))
+        return b, j, 0
+
     return pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=[
             pl.BlockSpec((bb, bq, d), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((bb, bk, d), lambda b, i, j: (b, j, 0)),
-            pl.BlockSpec((bb, bk, d), lambda b, i, j: (b, j, 0)),
+            pl.BlockSpec((bb, bk, d), kv_index),
+            pl.BlockSpec((bb, bk, d), kv_index),
         ],
         out_specs=pl.BlockSpec((bb, bq, d), lambda b, i, j: (b, i, 0)),
         out_shape=jax.ShapeDtypeStruct((bh, sq, d), q.dtype),

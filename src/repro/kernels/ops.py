@@ -84,7 +84,8 @@ def record_fallback(kernel: str, reason: str) -> None:
 
 def dispatch_counters() -> dict[str, int]:
     """Trace-time dispatch/fallback counts, keyed ``kernel/impl`` or
-    ``kernel/fallback/reason`` (obs surfaces these; tests reset them)."""
+    ``kernel/fallback/reason``; a compiled attention call also counts under
+    its tile, ``attention/pallas/tile/<bq>x<bk>`` (tests reset them)."""
     return dict(_DISPATCH_COUNTS)
 
 
@@ -389,8 +390,49 @@ def attention_fusable(sq: int, sk: int, d: int, dv: int, *,
     return True, None
 
 
+# Compiled flash-attention tiles, from an on-chip sweep (PERF.md §6): at
+# both benchmark shapes a call's time falls mostly with the number of kv
+# blocks a q block walks, each of which repeats the per-row online-softmax
+# update, and less with bq. 1024 x 1024 was 5-7% faster than 512 x 1024 but
+# needs twice the VMEM: a 4-byte head of 256 would not fit the 16 MiB there.
+FLASH_BQ_CAP, FLASH_BK_CAP = 512, 1024
+FLASH_VMEM_BUDGET = 8 << 20         # half the 16 MiB of scoped VMEM
+
+
+def flash_vmem_bytes(bq: int, bk: int, d: int, itemsize: int) -> int:
+    """Upper estimate of the compiled kernel's VMEM for (bq, bk) tiles at
+    head size d and operand itemsize: the f32 score and probability tiles,
+    the double-buffered q/k/v/o blocks and the f32 copies of q/k/v (head
+    size lane-padded to 128), and the f32 acc/m/l scratch (m and l
+    lane-padded)."""
+    lanes = padded_size(d, 128)
+    return (4 * 2 * bq * bk + itemsize * 2 * (2 * bq + 2 * bk) * lanes
+            + 4 * ((bq + 2 * bk) * lanes + bq * lanes + 2 * bq * 128))
+
+
 @functools.cache
-def _attention_fn(causal: bool, window: int, q_offset: int, impl: str):
+def flash_tiles(sq: int, sk: int, d: int, itemsize: int) -> tuple[int, int]:
+    """(bq, bk) of the compiled flash-attention grid for a call's shape:
+    the largest 128-multiples up to ``FLASH_BQ_CAP`` / ``FLASH_BK_CAP``
+    that divide the sequence lengths (the whole extent for one below 128),
+    halving bq's cap and then bk's until ``flash_vmem_bytes`` fits
+    ``FLASH_VMEM_BUDGET``. Every shape ``attention_fusable`` accepts gets
+    tiles."""
+    cq, ck = FLASH_BQ_CAP, FLASH_BK_CAP
+    while True:
+        bq, bk = _tile(sq, cq, 128), _tile(sk, ck, 128)
+        if ck == 128 or flash_vmem_bytes(bq, bk, d, itemsize) \
+                <= FLASH_VMEM_BUDGET:
+            return bq, bk
+        if cq > 128:
+            cq //= 2
+        else:
+            ck //= 2
+
+
+@functools.cache
+def _attention_fn(causal: bool, window: int, q_offset: int, impl: str,
+                  tiles: tuple[int, int] | None):
     def oracle(q, k, v):
         return ref.flash_attention_ref(q, k, v, causal=causal, window=window,
                                        q_offset=q_offset)
@@ -406,10 +448,10 @@ def _attention_fn(causal: bool, window: int, q_offset: int, impl: str):
                 bb=bh, bq=sq, bk=k.shape[1], interpret=True)
     else:
         def prim(q, k, v):
-            sq, sk = q.shape[1], k.shape[1]
+            bq, bk = tiles
             return flash_attention_pallas(
                 q, k, v, causal=causal, window=window, q_offset=q_offset,
-                bb=1, bq=min(128, sq), bk=min(128, sk), interpret=False)
+                bb=1, bq=bq, bk=bk, interpret=False)
 
     @jax.custom_vjp
     def fn(q, k, v):
@@ -433,7 +475,12 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
     ``attention_fusable`` first; this dispatch assumes a fusable shape."""
     impl = impl or _DEFAULT_IMPL
     record_dispatch("attention", impl)
-    return _attention_fn(causal, window, q_offset, impl)(q, k, v)
+    tiles = None
+    if impl == "pallas":
+        tiles = flash_tiles(q.shape[1], k.shape[1], q.shape[2],
+                            q.dtype.itemsize)
+        record_dispatch("attention", "pallas/tile/{}x{}".format(*tiles))
+    return _attention_fn(causal, window, q_offset, impl, tiles)(q, k, v)
 
 
 @functools.cache

@@ -6,6 +6,7 @@ shapes fall back with a structured warning + counter, never silently.
 hypothesis is an optional [test] extra: the property tests degrade to a
 skip when it is missing (same guard as tests/test_kernels.py).
 """
+import itertools
 import math
 import warnings
 
@@ -100,15 +101,39 @@ def test_layers_attention_gqa_bitwise_across_impls(h, hkv):
                                   np.asarray(outs["pallas_interpret"]))
 
 
-@pytest.mark.parametrize("causal,window", [(True, 0), (True, 100), (False, 0)])
-def test_multiblock_kernel_matches_oracle(causal, window):
-    """The production blocking (bq=bk=128, online-softmax rescales active)
-    matches the oracle to fp32 tolerance — no bitwise contract here."""
-    q, k, v = _qkv(2, 512, 512, 32, seed=3)
-    out = flash_attention_pallas(q, k, v, causal=causal, window=window,
-                                 bb=1, bq=128, bk=128, interpret=True)
-    ref = ops.flash_attention(q, k, v, causal=causal, window=window,
-                              impl="jnp")
+@pytest.mark.parametrize("causal,window,q_offset,sq,sk,d,bq,bk", [
+    pytest.param(True, 0, 0, 512, 512, 32, 128, 128, id="True-0"),
+    pytest.param(True, 100, 0, 512, 512, 32, 128, 128, id="True-100"),
+    pytest.param(False, 0, 0, 512, 512, 32, 128, 128, id="False-0"),
+    # tiles flash_tiles can choose; the causal k/v clamp skips the copies
+    pytest.param(True, 0, 0, 2048, 2048, 64, 512, 512, id="causal-512x512"),
+    pytest.param(True, 0, 0, 2048, 2048, 96, 256, 512, id="causal-256x512"),
+    pytest.param(True, 0, 0, 2048, 2048, 64, 512, 1024,
+                 id="causal-512x1024"),
+    pytest.param(True, 0, 0, 2048, 2048, 64, 1024, 512,
+                 id="causal-1024x512"),
+    # 1536 is no multiple of 1024 and 640 none of 256: the smaller tiles
+    pytest.param(True, 0, 0, 1536, 1536, 96, 512, 768, id="causal-s1536"),
+    pytest.param(True, 0, 0, 640, 640, 64, 128, 128, id="causal-s640"),
+    # a window moves the first kv block as well as the last
+    pytest.param(True, 300, 0, 1536, 1536, 64, 256, 256, id="window-256"),
+    pytest.param(True, 700, 0, 2048, 2048, 96, 512, 1024, id="window-512"),
+    # a later q chunk against a longer cache (static q_offset)
+    pytest.param(True, 0, 512, 1024, 1536, 64, 512, 768, id="q_offset"),
+    pytest.param(True, 300, 512, 1024, 1536, 96, 256, 512,
+                 id="q_offset-window"),
+    pytest.param(False, 0, 0, 1024, 1536, 96, 512, 768, id="non_causal"),
+])
+def test_multiblock_kernel_matches_oracle(causal, window, q_offset, sq, sk, d,
+                                          bq, bk):
+    """Multi-block tilings (online-softmax rescales active, k/v index maps
+    clamped to the blocks each q block needs) match the oracle to fp32
+    tolerance — no bitwise contract here."""
+    q, k, v = _qkv(2, sq, sk, d, seed=3)
+    kw = dict(causal=causal, window=window, q_offset=q_offset)
+    out = flash_attention_pallas(q, k, v, **kw, bb=1, bq=bq, bk=bk,
+                                 interpret=True)
+    ref = ops.flash_attention(q, k, v, **kw, impl="jnp")
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=2e-4, atol=2e-4)
 
@@ -136,6 +161,58 @@ def test_fallback_warns_once_and_counts():
         sp = jax.random.normal(ks[0], (1, 128, 2, 32))
         L.flash_attention(sp, sp, sp, softmax_scale=0.5)
     assert ops.dispatch_counters()["attention/fallback/custom_scale"] == 1
+
+
+def _grid_steps(bh, sq, sk, bq, bk):
+    return bh * (sq // bq) * (sk // bk)
+
+
+@pytest.mark.parametrize("bh,s,d", [(42, 4096, 64), (128, 2048, 96)],
+                         ids=["qwen2-0.5b", "gpt-neox-20b"])
+def test_flash_tiles_cut_the_grid(bh, s, d):
+    """At the benchmark cells' shapes (3 x 14 heads of 64 at 4096; 2 x 64
+    heads of 96 at 2048) the chosen tiles take at most 1/16 of the 128 x 128
+    grid's steps."""
+    for itemsize in (2, 4):
+        bq, bk = ops.flash_tiles(s, s, d, itemsize)
+        assert 16 * _grid_steps(bh, s, s, bq, bk) <= \
+            _grid_steps(bh, s, s, 128, 128), (itemsize, bq, bk)
+
+
+def test_flash_tiles_cover_every_fusable_shape():
+    """Every shape attention_fusable accepts gets tiles that divide its
+    lengths, are 128-aligned or whole, and fit the VMEM budget; the gate's
+    answers are those of its rule (lengths >= 8, 128-multiples above 128)."""
+    lengths = (4, 8, 64, 100, 128, 192, 384, 640, 1536, 2048, 4096, 8192)
+    for sq in lengths:
+        for sk in lengths:
+            for d, itemsize in itertools.product((32, 64, 96, 128, 256),
+                                                 (2, 4)):
+                ok, reason = ops.attention_fusable(sq, sk, d, d)
+                want = all(n >= 8 and (n < 128 or n % 128 == 0)
+                           for n in (sq, sk))
+                assert ok == want and (reason is None) == want, (sq, sk)
+                if not ok:
+                    continue
+                bq, bk = ops.flash_tiles(sq, sk, d, itemsize)
+                at = (sq, sk, d, itemsize, bq, bk)
+                for n, t in ((sq, bq), (sk, bk)):
+                    assert n % t == 0 and (t == n or t % 128 == 0), at
+                assert ops.flash_vmem_bytes(bq, bk, d, itemsize) <= \
+                    ops.FLASH_VMEM_BUDGET, at
+
+
+def test_dispatch_counts_the_compiled_tile():
+    """A compiled call counts under its tile at trace time (so a call left
+    at the old 128 x 128 grid shows in dispatch_counters)."""
+    bq, bk = ops.flash_tiles(1536, 2048, 96, 2)
+    q = jax.ShapeDtypeStruct((4, 1536, 96), jnp.bfloat16)
+    kv = jax.ShapeDtypeStruct((4, 2048, 96), jnp.bfloat16)
+    ops.reset_dispatch_counters()
+    jax.eval_shape(lambda q, k, v: ops.flash_attention(
+        q, k, v, causal=True, q_offset=512, impl="pallas"), q, kv, kv)
+    assert ops.dispatch_counters() == {
+        "attention/pallas": 1, f"attention/pallas/tile/{bq}x{bk}": 1}
 
 
 def test_attention_fusable_reasons():

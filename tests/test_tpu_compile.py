@@ -5,7 +5,8 @@ each kernel for a *described* chip (``jax.experimental.topologies``), so a
 block shape Mosaic refuses, a layout it cannot infer or a kernel that does
 not fit VMEM fails here instead of on the chip. Widths are qwen2-0.5b's
 (d 896, d_ff 4864, vocab 151,936, 14 heads of 64, seq 4096) with the
-launcher's quant block 128, and falcon-mamba-7b's d_inner for the scan.
+launcher's quant block 128, GPT-NeoX-20B's and gemma3-1b's heads for flash
+attention, and falcon-mamba-7b's d_inner for the scan.
 
 The topology is described inside a module-scoped fixture, never at import:
 only one process may load libtpu, and every xdist worker imports this file.
@@ -24,6 +25,8 @@ from repro.launch.mesh import make_device_mesh, zero_tiers
 from repro.models.registry import get_arch
 
 QWEN = get_arch("qwen2-0.5b")
+NEOX = get_arch("gpt-neox-20b")
+GEMMA = get_arch("gemma3-1b")
 MAMBA = get_arch("falcon-mamba-7b")
 BLOCK = 128
 ROWS = 2048
@@ -104,6 +107,29 @@ def test_flash_attention_fwd_compiles(one_chip):
     bh = 2 * QWEN.n_heads
     _compile(lambda q, k, v: ops.flash_attention(q, k, v, impl="pallas"),
              one_chip, *[((bh, 4096, QWEN.hdim), jnp.bfloat16)] * 3)
+
+
+def test_flash_attention_fwd_compiles_neox_widths(one_chip):
+    """GPT-NeoX-20B's 64 heads of 96 at 2048 tokens, 2 rows: the other
+    benchmark cell's tiles lower and fit VMEM."""
+    bh = 2 * NEOX.n_heads
+    _compile(lambda q, k, v: ops.flash_attention(q, k, v, impl="pallas"),
+             one_chip, *[((bh, 2048, NEOX.hdim), jnp.bfloat16)] * 3)
+
+
+@pytest.mark.parametrize("sq,sk,d,kw", [
+    (4096, 4096, GEMMA.hdim, dict(window=GEMMA.sliding_window)),
+    (1536, 2048, QWEN.hdim, dict(q_offset=512)),
+    (640, 1536, NEOX.hdim, dict(causal=False)),
+], ids=["gemma3_window", "prefill_chunk", "cross_attention"])
+def test_flash_attention_fwd_compiles_other_masks(one_chip, sq, sk, d, kw):
+    """The tiles of a sliding window at gemma3-1b's head size 256 (the
+    largest VMEM blocks), a later prefill chunk and non-causal attention of
+    lengths 1024 does not divide lower and fit VMEM."""
+    _compile(lambda q, k, v: ops.flash_attention(q, k, v, impl="pallas",
+                                                 **kw),
+             one_chip, ((8, sq, d), jnp.bfloat16),
+             *[((8, sk, d), jnp.bfloat16)] * 2)
 
 
 def test_selective_scan_compiles(one_chip):
