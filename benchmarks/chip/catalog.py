@@ -6,13 +6,22 @@ file and edits none.
 - ``traffic/<traffic>.json``: the job (scheme, shapes, token mix, optimizer);
 - ``limits/<cell>.json``: the limit of each number that decides ``correct``;
 - ``metrics/<metric>.py``: a per-layer reader, ``read(ctx) -> float | None``;
-- ``reference/<family>.py``: the plain float32 model a configuration names.
+- ``reference/<family>.py``: the plain float32 model a configuration names,
+  and, where it defines ``flops_per_token(cfg, table, seq_len)``, the
+  family's model FLOPs a token;
+- ``kernels/<prefix>.json``: a Pallas kernel whose roofline is read, by the
+  prefix of its HLO instruction name (the jitted wrapper's name in the
+  program's ``kernels/*.py``), with its metric and the work function that
+  prices one call: a name in ``flops.WORK``, or ``<module>.py`` under
+  ``kernels/`` defining ``work(operands, result)``.
 """
 from __future__ import annotations
 
 import importlib.util
 import json
 from pathlib import Path
+
+from . import flops
 
 HERE = Path(__file__).resolve().parent
 ROOT = HERE.parents[1]
@@ -54,13 +63,18 @@ def cell(name: str, bench: dict, base: Path = HERE, root: Path = ROOT) -> dict:
     if w["config"] not in confs:
         raise CellError(f"workload {name} names unknown config {w['config']!r}")
     entry = confs[w["config"]]
+    config = _json(root / entry["file"])
+    unlisted = sorted(set(config.get("program", {})) - set(entry["reduced"]))
+    if unlisted:
+        raise CellError(f"config {entry['name']}: the program block's "
+                        f"{unlisted} are not in its reduced list")
     metrics = [m for m in bench["per_layer"]
                if name in m.get("workloads", [name])]
     end_to_end = [m for m in bench["end_to_end"]
                   if name in m.get("workloads", [name])]
     return dict(
         name=name, chips=int(w["chips"]),
-        config=_json(root / entry["file"]), config_name=entry["name"],
+        config=config, config_name=entry["name"],
         traffic=_json(base / "traffic" / f"{w['traffic']}.json"),
         limits=_json(base / "limits" / f"{name}.json"),
         per_layer=metrics, end_to_end=end_to_end)
@@ -82,3 +96,21 @@ def reference(family: str):
 
 def load_json(name: str, base: Path = HERE) -> dict:
     return _json(base / name)
+
+
+def kernels(base: Path = HERE) -> dict:
+    """Every kernel entry, one a file ``kernels/<prefix>.json``:
+    prefix -> {"metric": name, "work": function}."""
+    out = {}
+    for f in sorted((base / "kernels").glob("*.json")):
+        e = _json(f)
+        work = e["work"]
+        if work.endswith(".py"):
+            fn = _module(base / "kernels" / work,
+                         "bench_kernel_" + work[:-3].replace(".", "_")).work
+        elif work in flops.WORK:
+            fn = flops.WORK[work]
+        else:
+            raise CellError(f"kernel {f.stem!r}: unknown work {work!r}")
+        out[f.stem] = dict(metric=e["metric"], work=fn)
+    return out

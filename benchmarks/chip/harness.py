@@ -114,6 +114,15 @@ def reference_readings(c: dict, table: dict, seed: int, devices, *,
             mesh=reference_mesh(devices))
 
 
+def flops_per_token(family, cfg: dict, table: dict, seq_len: int) -> float:
+    """Model FLOPs a trained token: the reference family's own count where
+    its module defines ``flops_per_token``, else the dense models'."""
+    own = getattr(family, "flops_per_token", None)
+    if own is not None:
+        return own(cfg, table, seq_len)
+    return flops.model_flops_per_token(cfg, table, seq_len)
+
+
 def peak_bytes(devices) -> int:
     """The fullest chip's high-water mark: its peak of live buffers plus
     its peak reservation for compiled programs' temporaries, which the TPU
@@ -141,7 +150,8 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool, *,
     c = catalog.cell(name, bench, base, root)
     devices = devices_for(c["chips"], require_tpu)
     cfg, traffic = c["config"], c["traffic"]
-    table = catalog.reference(cfg["reference"]).param_table(cfg)
+    family = catalog.reference(cfg["reference"])
+    table = family.param_table(cfg)
     prog = program.build(cfg, traffic, devices)
     weights.check_layout(table, prog.engine.specs)
     data = TokenStream.for_traffic(traffic, cfg["vocab_size"], seed)
@@ -180,8 +190,8 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool, *,
     kind = devices[0].device_kind
     ctx = dict(window_s=window_s, n_steps=n, step_times=step_times,
                tokens_per_step=tokens_per_step, chips=c["chips"],
-               flops_per_token=flops.model_flops_per_token(
-                   cfg, table, traffic["seq_len"]),
+               flops_per_token=flops_per_token(family, cfg, table,
+                                               traffic["seq_len"]),
                peaks=peaks.get(kind))
     out = dict(device=dict(device_info(devices), memory_peak_bytes=peak))
     if trace:
@@ -193,8 +203,7 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool, *,
         rec = xtrace.load(xtrace.find_xplane(str(trace_dir)))
         ctx["devices"] = xtrace.device_summary(rec)
         ctx["kernels"] = xtrace.kernel_summary(
-            rec, xtrace.custom_calls(hlo), catalog.load_json("kernels.json"),
-            ctx["peaks"], flops.WORK)
+            rec, xtrace.custom_calls(hlo), catalog.kernels(), ctx["peaks"])
         dev = ctx["devices"]
         busy = [ch["busy_ns"] for ch in dev["chips"].values()]
         out["device"].update(busy_s=sum(busy) / len(busy) * 1e-9,

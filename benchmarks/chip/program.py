@@ -6,8 +6,11 @@ own seeded weights and tokens; from the program it takes nothing else."""
 from __future__ import annotations
 
 import dataclasses
+import typing
 
 import jax
+
+from .catalog import CellError
 
 # configuration key (as the model's published config names it) -> the
 # program's ArchConfig field
@@ -22,17 +25,69 @@ ARCH_FIELDS = {
 
 def arch_for(config: dict):
     """The registry's architecture with the configuration's sizes applied
-    (the same values unless a size was cut, as ``reduced`` lists)."""
+    (the same values unless a size was cut, as ``reduced`` lists), then its
+    ``program`` block: ``ArchConfig`` field paths, dotted into nested
+    groups (``"moe.n_experts"``), to the values the program runs where it
+    departs from the registry. A field that a published key sets is refused
+    there: its cut shows in the published key and ``reduced``.
+
+    A depth cut of a patterned model keeps the registry pattern's first
+    ``n_layers`` kinds, so leading dense layers stay in place; it is refused
+    where it drops a kind of layer. ``program.block_pattern`` overrides it."""
     from repro.models.registry import get_arch
     base = get_arch(config["program_arch"])
     over = {f: config[k] for k, f in ARCH_FIELDS.items() if k in config}
     if "num_key_value_heads" not in config:  # one key/value head per query head
         over["n_kv_heads"] = over.get("n_heads", base.n_heads)
-    if base.block_pattern and over.get("n_layers", base.n_layers) != base.n_layers:
-        if len(set(base.block_pattern)) != 1:
-            raise ValueError("only a uniform layer pattern can be cut in depth")
-        over["block_pattern"] = (base.block_pattern[0],) * over["n_layers"]
-    return dataclasses.replace(base, **over)
+    arch = dataclasses.replace(base, **over)
+    block = config.get("program", {})
+    for path, value in block.items():
+        keys = [k for k, f in ARCH_FIELDS.items() if f == path]
+        if keys:
+            raise CellError(f"program.{path}: set by a published key "
+                            f"({' or '.join(keys)}), not by the block")
+        arch = _set(arch, path.split("."), value, path)
+    n = arch.n_layers
+    if base.block_pattern and n != base.n_layers and "block_pattern" not in block:
+        kinds = set(base.block_pattern)
+        if len(kinds) == 1:
+            pattern = (base.block_pattern[0],) * n
+        elif n > base.n_layers:
+            raise CellError(f"{base.name}'s pattern has {base.n_layers} layers, "
+                            f"not {n}; set program.block_pattern")
+        else:
+            pattern = base.block_pattern[:n]
+            lost = sorted(kinds - set(pattern))
+            if lost:
+                raise CellError(f"the first {n} layers of {base.name} drop the "
+                                f"kinds {lost}; keep a whole period")
+        arch = dataclasses.replace(arch, block_pattern=pattern)
+    if arch.block_pattern and len(arch.block_pattern) != n:
+        raise CellError(f"block_pattern has {len(arch.block_pattern)} kinds "
+                        f"for {n} layers")
+    return arch
+
+
+def _set(obj, keys: list[str], value, path: str):
+    """``obj`` with the field at ``keys`` set to ``value`` (a list as a
+    tuple), through ``dataclasses.replace`` at each level; a group the
+    registry leaves unset (``None``) starts from its type's defaults."""
+    name = keys[0]
+    if name not in {f.name for f in dataclasses.fields(obj)}:
+        raise CellError(f"program.{path}: {type(obj).__name__} has no field "
+                        f"{name!r}")
+    if len(keys) == 1:
+        value = tuple(value) if isinstance(value, list) else value
+    else:
+        inner = getattr(obj, name)
+        if inner is None:
+            hint = typing.get_type_hints(type(obj))[name]
+            inner = next((t() for t in typing.get_args(hint)
+                          if dataclasses.is_dataclass(t)), None)
+        if not dataclasses.is_dataclass(inner):
+            raise CellError(f"program.{path}: {name!r} is not a group of fields")
+        value = _set(inner, keys[1:], value, path)
+    return dataclasses.replace(obj, **{name: value})
 
 
 def hparams(traffic: dict):

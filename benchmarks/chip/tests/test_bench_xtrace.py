@@ -82,8 +82,7 @@ def test_kernel_join_on_the_compiled_step():
     rec = dict(window=[0, 1e9], host=[], devices={"0": dict(ops=[
         [n, "custom-call", i * 2e6, i * 2e6 + 1e6]
         for i, n in enumerate(dq + fa + ["fusion.9"])])})
-    k = xtrace.kernel_summary(rec, calls, catalog.load_json("kernels.json"),
-                              V5E, flops.WORK)
+    k = xtrace.kernel_summary(rec, calls, catalog.kernels(), V5E)
     assert k["dequant_matmul"]["calls"] == 20
     assert k["flash_attention_fwd"]["calls"] == 2
     f, b = flops.matmul(*calls["dequant_matmul_flat_pallas.196"])
@@ -92,6 +91,30 @@ def test_kernel_join_on_the_compiled_step():
     assert least < 1e-3      # so a 1 ms call reads under 100% of its roofline
     share = catalog.metric_reader("dequant_matmul_roofline")(dict(kernels=k))
     assert 0 < share < 100
+
+
+def test_kernel_work_of_every_call_as_before():
+    """Each kernel call of the compiled qwen2-0.5b step is priced as before
+    kernels could come from their own files: a matmul at 2 M C O, attention
+    at 4 BH pairs D with its query's head size for both matmuls."""
+    calls = xtrace.custom_calls(
+        (DATA / "qwen2-0.5b.zero_topo.s4096.custom_calls.hlo").read_text())
+    table = catalog.kernels()
+    priced = 0
+    for name, (ops, res) in calls.items():
+        prefix = xtrace.kernel_of(name, table)
+        if prefix is None:          # a kernel with no roofline metric
+            continue
+        priced += 1
+        nb = sum(flops.nbytes(o) for o in ops) + flops.nbytes(res)
+        if prefix == "flash_attention_pallas":
+            bh, s, d = ops[0][1]
+            want = 4 * bh * s * (ops[1][1][1] + 1) / 2 * d
+        else:
+            (_, x), (_, out) = ops[0], res
+            want = 2 * x[0] * x[1] * out[-1]
+        assert table[prefix]["work"](ops, res) == (want, nb), name
+    assert priced == 22
 
 
 def test_reduction_on_a_trace_from_the_chip():
@@ -106,8 +129,7 @@ def test_reduction_on_a_trace_from_the_chip():
     assert s["window_ns"] == 30e6
     assert 0.9 * 30e6 < chip["busy_ns"] <= 30e6
     assert chip["collective_ns"] == 0           # one chip: no collective
-    k = xtrace.kernel_summary(rec, calls, catalog.load_json("kernels.json"),
-                              V5E, flops.WORK)
+    k = xtrace.kernel_summary(rec, calls, catalog.kernels(), V5E)
     fa = k["flash_attention_fwd"]
     assert fa["calls"] == 1
     # the call's work by hand: q, k, v, out (42, 4096, 64) bf16, causal

@@ -178,11 +178,19 @@ def custom_calls(hlo_text: str) -> dict:
     return out
 
 
-def kernel_summary(rec: dict, calls: dict, table: dict, peaks: dict,
-                   work: dict) -> dict:
+def kernel_of(name: str, table: dict) -> str | None:
+    """The entry of ``table`` that an HLO instruction ``name`` is a call of:
+    the longest prefix it starts with, so ``flash_attention_pallas_bwd.3``
+    is the backward's call where both kernels have an entry."""
+    return max((p for p in table if name.startswith(p)), key=len, default=None)
+
+
+def kernel_summary(rec: dict, calls: dict, table: dict, peaks: dict) -> dict:
     """Per kernel metric: device time of all its calls inside the window, and
     the least time the chip could take for the same work, the larger of
-    operations over the bf16 peak and bytes over HBM bandwidth."""
+    operations over the bf16 peak and bytes over HBM bandwidth. ``table``:
+    prefix -> {"metric", "work": function of a call's shapes}
+    (``catalog.kernels``)."""
     lo, hi = window_of(rec)
     out = defaultdict(lambda: dict(time_s=0.0, least_s=0.0, calls=0,
                                    flops=0.0, bytes=0.0))
@@ -190,11 +198,11 @@ def kernel_summary(rec: dict, calls: dict, table: dict, peaks: dict,
         for name, _, s, e in d["ops"]:
             if s < lo or e > hi or name not in calls:   # whole calls only
                 continue
-            prefix = next((p for p in table if name.startswith(p)), None)
+            prefix = kernel_of(name, table)
             if prefix is None:
                 continue
             entry = table[prefix]
-            flops, nb = work[entry["work"]](*calls[name])
+            flops, nb = entry["work"](*calls[name])
             k = out[entry["metric"]]
             k["time_s"] += (e - s) * 1e-9
             k["least_s"] += max(flops / peaks["bf16_flops_per_s"],
